@@ -48,6 +48,8 @@ def main() -> None:
                     help="allow an interpret-mode run to overwrite a "
                          "record produced on a real backend")
     opts = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     which, json_path = opts.which, opts.json
     print("name,us_per_call,derived")
     mods = []

@@ -33,6 +33,10 @@ from repro.analysis.findings import Finding, error, info, warning
 # primitives whose params hold sub-jaxprs we must NOT descend into:
 # the block-sparse kernel body is dense per tile by design
 _OPAQUE_PRIMS = ("pallas_call",)
+# primitives that round-trip to the host (J203); ``jax.debug.print``
+# traces to ``debug_print`` since JAX 0.7, not to a "*callback" name
+_HOST_CALLBACK_PRIMS = frozenset({"debug_print", "debug_callback",
+                                  "pure_callback", "io_callback"})
 
 
 def collect_covered(plan_tree) -> Dict[Tuple[int, int], str]:
@@ -155,7 +159,7 @@ def audit_closure(fn, args: Iterable[Any], *,
         if name in _OPAQUE_PRIMS:
             n_pallas += 1
             continue
-        if "callback" in name and name not in cb_seen:
+        if name in _HOST_CALLBACK_PRIMS and name not in cb_seen:
             cb_seen.add(name)
             findings.append(warning(
                 "J203", where,

@@ -39,7 +39,7 @@ operands) host numpy):
 covering every registered kernel (bsmm fwd plain + fused epilogue, dx,
 dw, paged attention GQA + fused-V MLA, flash attention, masked matmul,
 tile stats); ``audit_kernels()`` runs them all and is what ``lint
---kernels`` invokes — the first gate of the TPU bring-up runbook.
+--kernels`` invokes — the static gate before a chip run.
 """
 from __future__ import annotations
 

@@ -350,12 +350,17 @@ class LMAdapter(ModelAdapter):
         qat = self._qat(quantize_bits)
         loss = (lambda p, batch:
                 self._tfm.loss_fn(qat(p), self.cfg, batch, plan=plan))
+        # the step donates params and optimizer state, so a retrain
+        # holds one copy of each (at published widths the f32 Adam
+        # moments alone fill a third of a 16 GB chip).  It donates a
+        # copy: the session rewinds to the caller's w_init every round.
+        params = jax.tree.map(jnp.copy, params)
         return Trainer(
             loss_fn=loss, optimizer=opt, params=params,
             data_iter=DataPipeline(self._batch, start_step=start_step,
                                    prefetch=0),
             ckpt_dir=ckpt_dir, ckpt_every=ckpt_every, async_ckpt=async_ckpt,
-            microbatch=self.microbatch, remat=self.remat, donate=False,
+            microbatch=self.microbatch, remat=self.remat, donate=True,
             step_deadline_s=self.step_deadline_s, compressor=compressor)
 
     def train(self, params, masks=None, steps=None, *, start_step: int = 0,

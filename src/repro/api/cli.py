@@ -381,14 +381,23 @@ def _fleet_report_dict(rep) -> dict:
             "per_engine": [_report_dict(p) for p in rep.per_engine]}
 
 
-def _serve_mesh(args):
-    """--mesh DxM → a virtual-device test mesh (None when unset)."""
+def _serve_meshes(args):
+    """One mesh per engine (None = meshless single-device engine).
+
+    ``--mesh DxM`` gives every engine its own DxM devices; a fleet
+    without it puts each engine on a device of its own when the host
+    has several, instead of stacking every replica on device 0."""
+    import jax
+
+    from repro.launch.mesh import make_fleet_meshes
+    n = max(args.engines, 1)
     spec = getattr(args, "mesh", None)
-    if not spec:
-        return None
-    from repro.launch.mesh import make_test_mesh
-    d, m = (int(x) for x in spec.lower().split("x"))
-    return make_test_mesh(d, m)
+    if spec:
+        d, m = (int(x) for x in spec.lower().split("x"))
+        return make_fleet_meshes(n, d, m)
+    if n > 1 and jax.device_count() > 1:
+        return make_fleet_meshes(n)
+    return [None] * n
 
 
 def _latency_line(rep) -> str:
@@ -443,9 +452,9 @@ def cmd_serve(args) -> int:
     else:
         params = adapter.init_params(jax.random.PRNGKey(args.seed))
         masks = None
-    mesh = _serve_mesh(args)
+    meshes = _serve_meshes(args)
 
-    def mk_engine():
+    def mk_engine(mesh=None):
         return ServeEngine(params=params, cfg=adapter.cfg,
                            prefill_fn=prefill_fn, decode_fn=decode_fn,
                            batch_slots=args.slots, capacity=args.capacity,
@@ -455,7 +464,7 @@ def cmd_serve(args) -> int:
     rng = np.random.RandomState(args.seed)
     if args.engines > 1:
         from repro.serve import FleetRouter
-        router = FleetRouter([mk_engine() for _ in range(args.engines)])
+        router = FleetRouter([mk_engine(m) for m in meshes])
         for i in range(args.requests):
             plen = (args.prompt_len if args.prompt_len
                     else rng.randint(4, 16))
@@ -472,7 +481,7 @@ def cmd_serve(args) -> int:
               f"{rep.requests} requests, {rep.tokens_generated} tokens "
               f"| {rep.tokens_per_s:.1f} tok/s | {_latency_line(rep)}")
         return EXIT_OK
-    engine = mk_engine()
+    engine = mk_engine(meshes[0])
     for i in range(args.requests):
         plen = args.prompt_len if args.prompt_len else rng.randint(4, 16)
         prompt = rng.randint(0, 200, size=plen)
@@ -540,10 +549,10 @@ def cmd_serve_daemon(args) -> int:
     heartbeat = (HeartbeatMonitor(args.heartbeat_dir,
                                   deadline_s=args.heartbeat_deadline)
                  if args.heartbeat_dir else None)
-    mesh = _serve_mesh(args)
+    meshes = _serve_meshes(args)
     fleet = args.engines > 1
 
-    def mk_engine(hb=None):
+    def mk_engine(mesh=None, hb=None):
         return ServeEngine(params=params, cfg=adapter.cfg,
                            prefill_fn=prefill_fn, decode_fn=decode_fn,
                            batch_slots=args.slots,
@@ -553,11 +562,11 @@ def cmd_serve_daemon(args) -> int:
 
     if fleet:
         from repro.serve import FleetRouter
-        router = FleetRouter([mk_engine() for _ in range(args.engines)],
+        router = FleetRouter([mk_engine(m) for m in meshes],
                              monitor=heartbeat, max_queue=args.max_queue)
         front, engine = router, router.frontends[0].engine
     else:
-        engine = mk_engine(hb=heartbeat)
+        engine = mk_engine(meshes[0], hb=heartbeat)
         router = None
         front = ServeFrontend(engine, max_queue=args.max_queue)
     rng = np.random.RandomState(args.seed)

@@ -242,6 +242,7 @@ class PruningSession:
         elif baseline is None:
             trained = adapter.train(w_init, masks)          # dense baseline
             baseline = float(adapter.evaluate(trained, masks))
+            del trained         # rounds retrain from w_init; free it now
             log.info("baseline accuracy: %.4f", baseline)
             self._save(state, masks, baseline, history)
 

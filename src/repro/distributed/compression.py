@@ -75,6 +75,10 @@ class MaskAwareCompressor:
     k_fraction: float = 1.0       # 1.0 = lossless w.r.t. surviving weights
 
     def init(self, params):
+        # lossless: nothing is ever dropped, so there is no residual to
+        # feed back — and no full-size f32 zeros to hold on the device
+        if self.k_fraction >= 1.0:
+            return None
         return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
 
     def compress(self, grads, residual):
@@ -138,7 +142,6 @@ def dp_allreduce_compressed(grads_fn, mesh, dp_axis: str, k_fraction: float):
     """Wrap a per-shard grad function with a compressed DP all-reduce
     under shard_map (used by the optional compressed train step)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def reduced(*args):
         def inner(*a):
@@ -146,7 +149,7 @@ def dp_allreduce_compressed(grads_fn, mesh, dp_axis: str, k_fraction: float):
             return jax.tree.map(
                 lambda t: compressed_psum(
                     t, dp_axis, max(1, int(k_fraction * t.size))), g)
-        return shard_map(inner, mesh=mesh,
-                         in_specs=P(dp_axis), out_specs=P())(*args)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=P(dp_axis), out_specs=P())(*args)
 
     return reduced

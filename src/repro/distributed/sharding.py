@@ -297,9 +297,11 @@ def install(rules: Optional[ShardingRules]):
     if rules is None:
         hooks.set_constrain_fn(lambda x, tags: x)
         hooks.set_moe_groups(1)
+        hooks.set_kernel_mesh(None)
     else:
         hooks.set_constrain_fn(rules.activation_constrainer())
         hooks.set_moe_groups(rules.dp_size)
+        hooks.set_kernel_mesh(rules.mesh)
 
 
 def installed() -> Optional[ShardingRules]:

@@ -32,8 +32,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import MXU_TILE
-from repro.kernels.compat import CompilerParams
 from repro.kernels.spec import BlockMap, KernelSpec, ScratchSpec
+from repro.models import hooks
 
 
 class GeometryError(ValueError):
@@ -61,10 +61,29 @@ class GeometryError(ValueError):
         super().__init__(" | ".join(parts))
 
 
-def default_interpret() -> bool:
-    """Emulate the Pallas kernels everywhere except on a real TPU
-    backend (interpret mode is a correctness path, not a fast path)."""
+def default_interpret(interpret: Optional[bool] = None) -> bool:
+    """Resolve a kernel's ``interpret`` argument: an explicit bool wins;
+    ``None`` emulates the Pallas kernels everywhere except on a real TPU
+    backend (interpret mode is a correctness path, not a fast path).
+    Every kernel entry point and plan builder resolves through here, so
+    a caller that passes nothing runs the compiled kernel on the chip."""
+    if interpret is not None:
+        return bool(interpret)
     return jax.default_backend() != "tpu"
+
+
+def launch(kernel, *args):
+    """Call a Pallas kernel.  Under an installed multi-device mesh
+    (``distributed.sharding.install``) the call runs inside a
+    ``shard_map`` that replicates every operand: the TPU compiler
+    cannot partition a Mosaic kernel, so each device runs the whole
+    launch on gathered operands and GSPMD inserts the gathers."""
+    mesh = hooks.kernel_mesh()
+    if mesh is None:
+        return kernel(*args)
+    rep = jax.sharding.PartitionSpec()
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(rep,) * len(args),
+                         out_specs=rep, check_vma=False)(*args)
 
 
 def tile_bitmap(mask: np.ndarray, bk: int = MXU_TILE,
@@ -159,12 +178,11 @@ def _bsmm_epilogue_kernel(count_ref, idx_ref, x_ref, w_ref, b_ref, o_ref,
 
 def bsmm_pallas(x, w, tile_mask: np.ndarray, *, bm: int = MXU_TILE,
                 bk: int = MXU_TILE, bn: int = MXU_TILE,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     """x: (M, K) @ block-sparse w: (K, N) → (M, N).
 
     ``tile_mask``: host numpy (⌈K/bk⌉, ⌈N/bn⌉) — static sparsity.
-    ``interpret=True`` runs the kernel body on CPU (this container);
-    on real TPU pass interpret=False.
+    ``interpret``: see ``default_interpret``.
     """
     M, K = x.shape
     K2, N = w.shape
@@ -177,7 +195,7 @@ def bsmm_pallas(x, w, tile_mask: np.ndarray, *, bm: int = MXU_TILE,
     idx, counts, kmax = compact_tile_indices(tile_mask)
     assert idx.shape[0] == N // bn and tile_mask.shape[0] == K // bk
     return _bsmm_compact(x, w, idx, counts, kmax, bm=bm, bk=bk, bn=bn,
-                         interpret=interpret)
+                         interpret=default_interpret(interpret))
 
 
 def bsmm_fwd_spec(idx, counts, kmax: int, *, M: int, K: int, N: int,
@@ -236,14 +254,15 @@ def _bsmm_compact(x, w, idx, counts, kmax: int, *, bm: int, bk: int,
             scratch_shapes=spec.pallas_scratch(),
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=CompilerParams(dimension_semantics=spec.dims),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
         interpret=interpret,
     )
     if fused:
         b = jnp.zeros((1, N), x.dtype) if bias is None \
             else jnp.asarray(bias).reshape(1, N)
-        return kernel(jnp.asarray(counts), jnp.asarray(idx), x, w, b)
-    return kernel(jnp.asarray(counts), jnp.asarray(idx), x, w)
+        return launch(kernel, jnp.asarray(counts), jnp.asarray(idx), x, w,
+                      b)
+    return launch(kernel, jnp.asarray(counts), jnp.asarray(idx), x, w)
 
 
 # ---------------------------------------------------------------------------
@@ -270,7 +289,7 @@ class TilePlan(NamedTuple):
     tile: int               # square tile edge (the MXU/crossbar 128)
     live_tiles: int
     total_tiles: int
-    interpret: bool = True
+    interpret: bool = False  # resolved by make_tile_plan
     idx_t: Optional[np.ndarray] = None    # (Kt, NMAX) live N-tile ids per row
     counts_t: Optional[np.ndarray] = None  # (Kt,)
     nmax: int = 1
@@ -279,7 +298,7 @@ class TilePlan(NamedTuple):
 
 
 def make_tile_plan(mask: np.ndarray, *, tile: int = MXU_TILE,
-                   interpret: bool = True,
+                   interpret: Optional[bool] = None,
                    strict: bool = False,
                    where: str = "make_tile_plan") -> Optional[TilePlan]:
     """Elementwise {0,1} mask (K, N) → ``TilePlan``.
@@ -288,7 +307,8 @@ def make_tile_plan(mask: np.ndarray, *, tile: int = MXU_TILE,
     dense fallback) — or, with ``strict=True``, raises a structured
     ``GeometryError`` naming the shape/tile/location, for callers that
     expect the geometry to hold (lint, tests, TPU launches).  An
-    invalid ``tile`` always raises.
+    invalid ``tile`` always raises.  ``interpret`` is resolved here
+    (``default_interpret``) and fixed in the plan.
     """
     if tile <= 0:
         raise GeometryError(f"tile edge must be positive, got {tile}",
@@ -311,7 +331,8 @@ def make_tile_plan(mask: np.ndarray, *, tile: int = MXU_TILE,
     kk, nn = np.nonzero(bitmap)
     return TilePlan(idx=idx, counts=counts, kmax=kmax, tile=tile,
                     live_tiles=int(bitmap.sum()),
-                    total_tiles=int(bitmap.size), interpret=interpret,
+                    total_tiles=int(bitmap.size),
+                    interpret=default_interpret(interpret),
                     idx_t=idx_t, counts_t=counts_t, nmax=nmax,
                     kk=kk.astype(np.int32), nn=nn.astype(np.int32))
 
@@ -390,10 +411,11 @@ def _bsmm_dx(g, w, plan: TilePlan, *, bm: int):
             scratch_shapes=spec.pallas_scratch(),
         ),
         out_shape=jax.ShapeDtypeStruct((M, K), g.dtype),
-        compiler_params=CompilerParams(dimension_semantics=spec.dims),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
         interpret=plan.interpret,
     )
-    return kernel(jnp.asarray(plan.counts_t), jnp.asarray(plan.idx_t), g, w)
+    return launch(kernel, jnp.asarray(plan.counts_t),
+                  jnp.asarray(plan.idx_t), g, w)
 
 
 def _bsmm_dw_kernel(kk_ref, nn_ref, x_ref, g_ref, o_ref, acc_ref):
@@ -472,10 +494,10 @@ def _bsmm_dw(x2, g, plan: TilePlan, *, bm: int, out_dtype):
             scratch_shapes=spec.pallas_scratch(),
         ),
         out_shape=jax.ShapeDtypeStruct((L, bk, bn), out_dtype),
-        compiler_params=CompilerParams(dimension_semantics=spec.dims),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
         interpret=plan.interpret,
     )
-    tiles = kernel(jnp.asarray(plan.kk), jnp.asarray(plan.nn), x2, g)
+    tiles = launch(kernel, jnp.asarray(plan.kk), jnp.asarray(plan.nn), x2, g)
     dw = jnp.zeros((Kt, Nt, bk, bn), out_dtype)
     dw = dw.at[jnp.asarray(plan.kk), jnp.asarray(plan.nn)].set(tiles)
     return dw.transpose(0, 2, 1, 3).reshape(K, N)
@@ -667,7 +689,7 @@ def masked_matmul_spec(*, M: int, K: int, N: int, bm: int, bk: int,
 
 def masked_matmul_pallas(x, w, mask, *, bm: int = MXU_TILE,
                          bk: int = MXU_TILE, bn: int = MXU_TILE,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """Elementwise-masked matmul with per-tile MXU skip (no DMA skip)."""
     M, K = x.shape
     _, N = w.shape
@@ -683,7 +705,7 @@ def masked_matmul_pallas(x, w, mask, *, bm: int = MXU_TILE,
         out_specs=spec.pallas_out_specs()[0],
         scratch_shapes=spec.pallas_scratch(),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=CompilerParams(dimension_semantics=spec.dims),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
+        interpret=default_interpret(interpret),
     )
-    return kernel(x, w, mask)
+    return launch(kernel, x, w, mask)

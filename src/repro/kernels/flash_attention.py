@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import MXU_TILE
-from repro.kernels.compat import CompilerParams
+from repro.kernels.bsmm import default_interpret, launch
 from repro.kernels.spec import BlockMap, KernelSpec, ScratchSpec
 
 NEG_INF = -1e30
@@ -107,7 +108,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     bq: int = MXU_TILE, bk: int = MXU_TILE,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B, S, Hq, hd); k/v: (B, S, Hkv, hd) → (B, S, Hq, hd)."""
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
@@ -128,8 +129,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
         out_specs=spec.pallas_out_specs()[0],
         out_shape=jax.ShapeDtypeStruct((B, Hq, S, hd), q.dtype),
         scratch_shapes=spec.pallas_scratch(),
-        compiler_params=CompilerParams(dimension_semantics=spec.dims),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
+        interpret=default_interpret(interpret),
     )
-    out = kernel(qt, kt, vt)
+    out = launch(kernel, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -8,6 +8,8 @@ configs) and on platforms without Pallas TPU support.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -26,7 +28,7 @@ def tile_density(mask: np.ndarray, bk: int = MXU_TILE,
 
 
 def sparse_dense(x, w, mask: np.ndarray, *, bk: int = MXU_TILE,
-                 bn: int = MXU_TILE, interpret: bool = True):
+                 bn: int = MXU_TILE, interpret: Optional[bool] = None):
     """x (..., K) @ pruned w (K, N) skipping dead 128×128 tiles.
 
     mask: host numpy elementwise {0,1} (static — pruning is offline).
@@ -50,7 +52,7 @@ def sparse_dense(x, w, mask: np.ndarray, *, bk: int = MXU_TILE,
 
 
 def tile_stats(w, *, bk: int = MXU_TILE, bn: int = MXU_TILE,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     """Device-side per-tile (liveness, Σ|w|); pads ragged edges."""
     K, N = w.shape
     pk, pn = (-K) % bk, (-N) % bn

@@ -47,8 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import MXU_TILE
-from repro.kernels.bsmm import GeometryError, default_interpret
-from repro.kernels.compat import CompilerParams
+from repro.kernels.bsmm import GeometryError, default_interpret, launch
 from repro.kernels.spec import BlockMap, KernelSpec, ScratchSpec
 
 #: tokens per KV block — one MXU tile edge, like the bsmm tile
@@ -275,8 +274,6 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     mirroring ``bsmm``.
     """
     geo = _check_geometry(q, k_pool, v_pool, tables, lengths, v_dim)
-    if interpret is None:
-        interpret = default_interpret()
     fused = v_pool is None
     spec = paged_attention_spec(geo, tables, lengths, fused_v=fused,
                                 dtype=q.dtype)
@@ -294,12 +291,12 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
             out_specs=spec.pallas_out_specs()[0],
             scratch_shapes=spec.pallas_scratch()),
         out_shape=jax.ShapeDtypeStruct((geo.B, geo.Hq, geo.dv), q.dtype),
-        compiler_params=CompilerParams(dimension_semantics=spec.dims),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
+        interpret=default_interpret(interpret),
     )
     if fused:
-        return kernel(tables, lengths, q, k_pool)
-    return kernel(tables, lengths, q, k_pool, v_pool)
+        return launch(kernel, tables, lengths, q, k_pool)
+    return launch(kernel, tables, lengths, q, k_pool, v_pool)
 
 
 def paged_gather(pool, tables):

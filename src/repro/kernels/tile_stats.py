@@ -7,6 +7,8 @@ after a checkpoint restore without a host round-trip).
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import MXU_TILE
+from repro.kernels.bsmm import default_interpret, launch
 from repro.kernels.spec import BlockMap, KernelSpec
 
 
@@ -45,7 +48,8 @@ def _tile_stats_kernel(w_ref, live_ref, sum_ref):
     live_ref[0, 0] = (jnp.any(blk != 0)).astype(jnp.int32)
 
 
-def tile_stats_for_config(w, prune_cfg, *, interpret: bool = True):
+def tile_stats_for_config(w, prune_cfg, *,
+                          interpret: Optional[bool] = None):
     """Tile stats at a ``PruneConfig``'s crossbar geometry.
 
     The tile extents come from ``prune_cfg.xbar_rows/xbar_cols`` so the
@@ -61,7 +65,7 @@ def tile_stats_for_config(w, prune_cfg, *, interpret: bool = True):
 
 
 def tile_stats_pallas(w, *, bk: int = MXU_TILE, bn: int = MXU_TILE,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """w: (K, N) → (live (Kt, Nt) int32, sums (Kt, Nt) f32)."""
     K, N = w.shape
     assert K % bk == 0 and N % bn == 0, (w.shape, bk, bn)
@@ -73,6 +77,6 @@ def tile_stats_pallas(w, *, bk: int = MXU_TILE, bn: int = MXU_TILE,
         out_specs=spec.pallas_out_specs(),
         out_shape=[jax.ShapeDtypeStruct((K // bk, N // bn), jnp.int32),
                    jax.ShapeDtypeStruct((K // bk, N // bn), jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )
-    return kernel(w)
+    return launch(kernel, w)

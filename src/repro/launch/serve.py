@@ -12,10 +12,10 @@ wedges.  ``--engines N`` fronts N engines with a ``FleetRouter``
 (least-loaded dispatch + heartbeat failover); ``--mesh DxM`` runs each
 engine sharded over a (data, model) test mesh (virtual devices on CPU —
 launch with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
-Same mesh/sharding story as train.py: ``--smoke`` runs the reduced
-config on CPU; the full configs' serve_step lowering for the production
-meshes is proven by ``repro.launch.dryrun`` (prefill_32k / decode_32k /
-long_500k cells).
+Same mesh/sharding story as train.py: ``--smoke`` serves the reduced
+f32 config; without it the registered config serves as published, on
+``--mesh`` or else on the mesh ``launch.mesh.make_launch_mesh`` builds
+from the devices present.
 """
 from __future__ import annotations
 
@@ -29,7 +29,9 @@ from repro.core import algorithm as alg
 from repro.core.masks import apply_masks, lm_prunable, make_masks, \
     sparsity_fraction
 from repro.distributed.fault_tolerance import HeartbeatMonitor
-from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import (make_fleet_meshes, make_launch_mesh,
+                               make_test_mesh)
 from repro.models import transformer as tfm
 from repro.serve import FleetRouter, ServeEngine, ServeFrontend
 
@@ -60,14 +62,16 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
 
-    n_dev = len(jax.devices())
-    if args.smoke or n_dev == 1 or args.mesh:
-        cfg = scaled_down(get_arch(args.arch), dtype="float32")
-        mesh = (make_test_mesh(*parse_mesh(args.mesh)) if args.mesh
-                else make_test_mesh())
-    else:  # pragma: no cover
-        cfg = get_arch(args.arch)
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+    use_compile_cache()
+    cfg = get_arch(args.arch)
+    if args.smoke:
+        cfg = scaled_down(cfg, dtype="float32")
+    if args.mesh:
+        mesh = make_test_mesh(*parse_mesh(args.mesh))
+    elif args.smoke:
+        mesh = make_test_mesh()
+    else:
+        mesh = make_launch_mesh(multi_pod=args.multi_pod)
 
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
     masks = None
@@ -84,7 +88,7 @@ def main():
     monitor = (HeartbeatMonitor(args.heartbeat_dir, deadline_s=30.0)
                if args.heartbeat_dir else None)
 
-    def make_engine(heartbeat=None, worker="engine"):
+    def make_engine(mesh, heartbeat=None, worker="engine"):
         # engines install the rules scoped around their own traces, so
         # a fleet of sharded engines coexists in one process
         return ServeEngine(params=params, cfg=cfg,
@@ -96,8 +100,11 @@ def main():
 
     rng = np.random.RandomState(0)
     if args.engines > 1:
-        router = FleetRouter([make_engine() for _ in range(args.engines)],
-                             monitor=monitor)
+        # one replica per device (or per --mesh-sized device group)
+        d, m = parse_mesh(args.mesh) if args.mesh else (1, 1)
+        router = FleetRouter(
+            [make_engine(em) for em in make_fleet_meshes(args.engines, d, m)],
+            monitor=monitor)
         for i in range(args.requests):
             router.submit(
                 rng.randint(0, 200, rng.randint(4, 32)).astype(np.int32),
@@ -116,7 +123,7 @@ def main():
               f"deadline misses {rep.deadline_misses}")
         return
 
-    engine = make_engine(heartbeat=monitor)
+    engine = make_engine(mesh, heartbeat=monitor)
     frontend = ServeFrontend(engine)
     for i in range(args.requests):
         frontend.submit(

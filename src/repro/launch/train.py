@@ -3,11 +3,11 @@
     PYTHONPATH=src python -m repro.launch.train --arch yi-6b \
         [--smoke] [--steps N] [--ckpt DIR] [--zero1] [--pruned FRAC]
 
-On this CPU container use ``--smoke`` (reduced same-family config, real
-data/optimizer/checkpoint stack).  On a real TPU pod the same script
-builds the production mesh, installs the sharding rules and runs the
-identical code path — the dry-run (``repro.launch.dryrun``) proves every
-assigned config compiles for that path.
+``--smoke`` trains a reduced same-family f32 config (real data,
+optimizer and checkpoint stack) — the CPU path.  Without it the
+registered config trains as published, on the mesh
+``launch.mesh.make_launch_mesh`` builds from the devices present: one
+chip, a four-chip host, or the production mesh on a whole pod.
 
 Pipeline-parallelism note: PP is intentionally not used (DESIGN.md §4);
 scan-over-layers + TP/EP/SP covers the assigned scales.  A PP stage
@@ -26,7 +26,8 @@ from repro.configs import get_arch, scaled_down
 from repro.data import DataPipeline, SyntheticLM
 from repro.distributed.fault_tolerance import SkipStraggler, Supervisor
 from repro.distributed.sharding import ShardingRules, install
-from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_launch_mesh, make_test_mesh
 from repro.models import encdec
 from repro.models import transformer as tfm
 from repro.optim import adamw, masked, warmup_cosine
@@ -46,21 +47,20 @@ def main():
     ap.add_argument("--zero1", action="store_true")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    use_compile_cache()
 
-    n_dev = len(jax.devices())
-    if args.smoke or n_dev == 1:
-        cfg = scaled_down(get_arch(args.arch), dtype="float32")
+    cfg = get_arch(args.arch)
+    if args.smoke:
+        cfg = scaled_down(cfg, dtype="float32")
         mesh = make_test_mesh()
-    else:  # pragma: no cover — real-pod path, proven by the dry-run
-        cfg = get_arch(args.arch)
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+    else:
+        mesh = make_launch_mesh(multi_pod=args.multi_pod)
     rules = ShardingRules(mesh)
     install(rules)
 
     mod = encdec if cfg.is_encoder_decoder else tfm
     params = mod.init_params(jax.random.PRNGKey(0), cfg)
-    if n_dev > 1:  # pragma: no cover
-        params = jax.device_put(params, rules.params_shardings(params))
+    params = jax.device_put(params, rules.params_shardings(params))
 
     gen = SyntheticLM(vocab_size=min(cfg.vocab_size, 1024), seq_len=args.seq)
 

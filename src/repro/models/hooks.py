@@ -9,6 +9,7 @@ from __future__ import annotations
 
 _CONSTRAIN = lambda x, tags: x  # noqa: E731
 _MOE_GROUPS = 1
+_KERNEL_MESH = None
 
 
 def set_constrain_fn(fn):
@@ -33,3 +34,15 @@ def set_moe_groups(g: int):
 
 def moe_groups() -> int:
     return _MOE_GROUPS
+
+
+def set_kernel_mesh(mesh):
+    """The mesh Pallas kernels launch over (``kernels.bsmm.launch``);
+    None — or a one-device mesh — launches them as plain calls."""
+    global _KERNEL_MESH
+    size = 1 if mesh is None else mesh.devices.size
+    _KERNEL_MESH = mesh if size > 1 else None
+
+
+def kernel_mesh():
+    return _KERNEL_MESH

@@ -72,7 +72,7 @@ def _union_mask(mask) -> Optional[np.ndarray]:
 
 
 def _plan_group(masks: Dict[str, Any], keys, label: str, stats: PlanStats,
-                *, tile: int, interpret: bool,
+                *, tile: int, interpret: Optional[bool],
                 strict: bool = False) -> Optional[Dict[str, TilePlan]]:
     group: Dict[str, TilePlan] = {}
     for key in keys:
@@ -94,7 +94,8 @@ def _plan_group(masks: Dict[str, Any], keys, label: str, stats: PlanStats,
 
 
 def build_decode_plan(masks, *, tile: int = MXU_TILE,
-                      interpret: bool = True, strict: bool = False
+                      interpret: Optional[bool] = None,
+                      strict: bool = False
                       ) -> Tuple[Optional[list], PlanStats]:
     """Mask pytree → (plan mirroring params['segments'], PlanStats).
 

@@ -445,7 +445,7 @@ def supports_masked_prefill(cfg: ArchConfig) -> bool:
     ``valid_len`` lane), so they are exact-length too."""
     try:
         kinds = set(cfg.blocks)
-    except Exception:
+    except AttributeError:          # not a decoder-LM config (CNN)
         return False
     return (kinds == {ATTN} and not cfg.num_patch_tokens
             and cfg.moe is None and not cfg.is_encoder_decoder)
@@ -536,7 +536,7 @@ def supports_paged_decode(cfg: ArchConfig) -> bool:
     pages; encoder-decoder archs decode through ``models.encdec``."""
     try:
         kinds = set(cfg.blocks)
-    except Exception:
+    except AttributeError:          # not a decoder-LM config (CNN)
         return False
     return kinds == {ATTN} and not cfg.is_encoder_decoder
 

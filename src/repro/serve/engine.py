@@ -60,7 +60,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.bsmm import default_interpret
 from repro.kernels.paged_attention import BLOCK_TOKENS
+from repro.models import transformer as tfm
 from repro.serve.paging import BlockPool, blocks_needed
 from repro.serve.ticket import PlanStats, build_decode_plan
 
@@ -291,31 +293,15 @@ class ServeEngine:
         self._prefill_fn = prefill_fn
         self._decode_fn = decode_fn
 
-        # interpret=None → emulate the Pallas kernel everywhere except
-        # on a real TPU backend (interpret mode is a correctness path,
-        # not a fast path)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        self._interpret = interpret
+        self._interpret = default_interpret(interpret)
         self._use_bsmm = use_bsmm
 
         # -- masked (bucketed) vs exact-length prefill ------------------
-        try:
-            from repro.models.transformer import supports_masked_prefill
-            self._masked_prefill = supports_masked_prefill(cfg)
-        except Exception:
-            self._masked_prefill = False
+        self._masked_prefill = tfm.supports_masked_prefill(cfg)
 
         # -- paged KV cache ---------------------------------------------
-        self._tfm = None
-        paged_ok = False
-        try:
-            from repro.models import transformer as _tfm
-            self._tfm = _tfm
-            paged_ok = (_tfm.supports_paged_decode(cfg)
-                        and decode_fn is _tfm.decode_step)
-        except Exception:
-            pass
+        paged_ok = (tfm.supports_paged_decode(cfg)
+                    and decode_fn is tfm.decode_step)
         if paged is None:
             paged = paged_ok
         elif paged and not paged_ok:
@@ -437,7 +423,6 @@ class ServeEngine:
             slot_gens=[None] * self.slots,
             cur=np.zeros((self.slots,), np.int32))
         if self.paged:
-            tfm = self._tfm
             gen.pool = BlockPool(self.kv_blocks)
             gen.paged_caches = self._shard_caches(
                 tfm.make_paged_caches(cfg, self.kv_blocks))
@@ -598,9 +583,10 @@ class ServeEngine:
     def _cache_axes(self, proto):
         if self._axes is None:
             try:
-                from repro.models.transformer import cache_batch_axes
-                self._axes = cache_batch_axes(self.cfg, proto)
-            except Exception:
+                self._axes = tfm.cache_batch_axes(self.cfg, proto)
+            except ValueError:
+                # not a segment-structured decoder cache (the enc-dec
+                # lane): every leaf is batch-leading
                 self._axes = jax.tree.map(lambda _: 0, proto)
         return self._axes
 

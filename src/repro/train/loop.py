@@ -10,6 +10,7 @@ a straggler/failure policy hook.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import time
 from dataclasses import dataclass
@@ -124,6 +125,11 @@ def init_opt_state(optimizer: Optimizer, params, compressor=None):
     return state
 
 
+def _warn_straggler(step: int, dt: float, *, deadline: Optional[float]):
+    log.warning("straggler: step %d took %.2fs (deadline %.2fs)", step, dt,
+                deadline)
+
+
 class Trainer:
     """Operational wrapper: resume → train → checkpoint → (survive)."""
 
@@ -152,10 +158,11 @@ class Trainer:
             params, init_opt_state(optimizer, params, compressor), 0,
             aux_state)
         self.step_deadline_s = step_deadline_s
-        self.on_straggler = on_straggler or (
-            lambda step, dt: log.warning(
-                "straggler: step %d took %.2fs (deadline %.2fs)", step, dt,
-                self.step_deadline_s))
+        # no reference back to self: a Trainer must be freed the moment
+        # its caller drops it, not at the next cyclic GC — its state can
+        # hold most of the device's memory
+        self.on_straggler = on_straggler or functools.partial(
+            _warn_straggler, deadline=step_deadline_s)
         self._maybe_resume()
 
     def _maybe_resume(self):

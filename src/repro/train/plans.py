@@ -21,7 +21,7 @@ from typing import Any, Optional, Tuple
 import numpy as np
 
 from repro.configs.base import MXU_TILE
-from repro.kernels.bsmm import default_interpret, make_tile_plan
+from repro.kernels.bsmm import make_tile_plan
 from repro.models.plans import PlanStats, build_decode_plan
 
 
@@ -34,8 +34,6 @@ def lm_train_plan(masks, *, tile: int = MXU_TILE,
     ``models.plans.build_decode_plan``) — conservative but exact, since
     pruned weights are exact zeros.
     """
-    if interpret is None:
-        interpret = default_interpret()
     return build_decode_plan(masks, tile=tile, interpret=interpret)
 
 
@@ -46,8 +44,6 @@ def cnn_train_plan(masks, *, tile: int = MXU_TILE,
     PlanStats) for ``models.cnn.forward`` — or (None, stats) when no FC
     or head weight is routable (shapes that don't tile stay dense)."""
     stats = PlanStats()
-    if interpret is None:
-        interpret = default_interpret()
     if not isinstance(masks, dict):
         return None, stats
 

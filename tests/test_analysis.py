@@ -436,8 +436,7 @@ def test_routed_closure_is_clean(plan, mask):
 
 
 def test_j202_f64_promotion():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         @jax.jit
         def f(x):
             return x.astype(jnp.float64) * 2.0

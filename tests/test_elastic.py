@@ -15,6 +15,7 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.checkpoint import CheckpointManager
     from repro.distributed.sharding import ShardingRules, install
+    from repro.launch.mesh import make_test_mesh
     from repro.models import transformer as tfm
     from repro.configs import get_arch, scaled_down
 
@@ -22,8 +23,8 @@ SCRIPT = textwrap.dedent("""
     cfg = scaled_down(get_arch("llama3.2-3b"), dtype="float32",
                       d_model=128, n_heads=4, n_kv_heads=4, head_dim=32)
 
-    def make(mesh_shape, axes):
-        mesh = jax.make_mesh(mesh_shape, axes)
+    def make(mesh_shape):
+        mesh = make_test_mesh(*mesh_shape)
         rules = ShardingRules(mesh)
         install(rules)
         return mesh, rules
@@ -33,7 +34,7 @@ SCRIPT = textwrap.dedent("""
              "labels": jnp.ones((8, 16), jnp.int32)}
 
     # phase 1: big mesh (2 data × 4 model) — train one step, checkpoint
-    mesh, rules = make((2, 4), ("data", "model"))
+    mesh, rules = make((2, 4))
     p1 = jax.device_put(params, rules.params_shardings(params))
     with mesh:
         loss1, _ = jax.jit(lambda p, b: tfm.loss_fn(p, cfg, b))(p1, batch)
@@ -41,7 +42,7 @@ SCRIPT = textwrap.dedent("""
     mgr.save(1, {"params": p1})
 
     # phase 2: "lost half the hosts" — restore onto (2 data × 2 model)
-    mesh2, rules2 = make((2, 2), ("data", "model"))
+    mesh2, rules2 = make((2, 2))
     template = {"params": jax.tree.map(jnp.zeros_like, params)}
     step, tree = mgr.restore(
         template, shardings={"params": rules2.params_shardings(params)})

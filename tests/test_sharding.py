@@ -95,9 +95,10 @@ SUBPROCESS_TEST = textwrap.dedent("""
     import numpy as np
     from repro.configs import get_arch, scaled_down
     from repro.distributed.sharding import ShardingRules, install
+    from repro.launch.mesh import make_test_mesh
     from repro.models import transformer as tfm
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh(2, 4)
     rules = ShardingRules(mesh)
     install(rules)
     cfg = scaled_down(get_arch("yi-6b"), dtype="float32", d_model=128,
