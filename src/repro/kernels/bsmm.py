@@ -256,6 +256,7 @@ def _bsmm_compact(x, w, idx, counts, kmax: int, *, bm: int, bk: int,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
         interpret=interpret,
+        name=spec.name,
     )
     if fused:
         b = jnp.zeros((1, N), x.dtype) if bias is None \
@@ -413,6 +414,7 @@ def _bsmm_dx(g, w, plan: TilePlan, *, bm: int):
         out_shape=jax.ShapeDtypeStruct((M, K), g.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
         interpret=plan.interpret,
+        name=spec.name,
     )
     return launch(kernel, jnp.asarray(plan.counts_t),
                   jnp.asarray(plan.idx_t), g, w)
@@ -496,6 +498,7 @@ def _bsmm_dw(x2, g, plan: TilePlan, *, bm: int, out_dtype):
         out_shape=jax.ShapeDtypeStruct((L, bk, bn), out_dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
         interpret=plan.interpret,
+        name=spec.name,
     )
     tiles = launch(kernel, jnp.asarray(plan.kk), jnp.asarray(plan.nn), x2, g)
     dw = jnp.zeros((Kt, Nt, bk, bn), out_dtype)
@@ -707,5 +710,6 @@ def masked_matmul_pallas(x, w, mask, *, bm: int = MXU_TILE,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
         interpret=default_interpret(interpret),
+        name=spec.name,
     )
     return launch(kernel, x, w, mask)

@@ -131,6 +131,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
         scratch_shapes=spec.pallas_scratch(),
         compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
         interpret=default_interpret(interpret),
+        name=spec.name,
     )
     out = launch(kernel, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

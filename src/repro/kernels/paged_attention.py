@@ -293,6 +293,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         out_shape=jax.ShapeDtypeStruct((geo.B, geo.Hq, geo.dv), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=spec.dims),
         interpret=default_interpret(interpret),
+        name=spec.name,
     )
     if fused:
         return launch(kernel, tables, lengths, q, k_pool)

@@ -78,5 +78,6 @@ def tile_stats_pallas(w, *, bk: int = MXU_TILE, bn: int = MXU_TILE,
         out_shape=[jax.ShapeDtypeStruct((K // bk, N // bn), jnp.int32),
                    jax.ShapeDtypeStruct((K // bk, N // bn), jnp.float32)],
         interpret=default_interpret(interpret),
+        name=spec.name,
     )
     return launch(kernel, w)

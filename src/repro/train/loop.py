@@ -18,12 +18,18 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import CheckpointManager
 from repro.core.masks import apply_masks
 from repro.optim import Optimizer
 
 log = logging.getLogger("train")
+
+# the named scope of the gradient compression and optimizer update in
+# the step's HLO (each op's ``op_name`` metadata); profilers group the
+# device time of the update under it
+OPTIMIZER_SCOPE = "train.optimizer"
 
 
 @dataclass
@@ -68,7 +74,9 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
 
             (loss, (new_state, metrics)), grads = jax.value_and_grad(
                 inner, has_aux=True)(params)
-            new_params, new_opt = optimizer.update(grads, opt_state, params)
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                new_params, new_opt = optimizer.update(grads, opt_state,
+                                                       params)
             metrics = dict(metrics)
             metrics["loss"] = loss
             return new_params, new_opt, new_state, metrics
@@ -104,10 +112,12 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
             loss = loss / n
             metrics = {}
         metrics = dict(metrics)
-        if compressor is not None:
-            grads, residual, cstats = compressor.compress(grads, residual)
-            metrics["sent_fraction"] = cstats["sent_fraction"]
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            if compressor is not None:
+                grads, residual, cstats = compressor.compress(grads,
+                                                              residual)
+                metrics["sent_fraction"] = cstats["sent_fraction"]
+            new_params, new_opt = optimizer.update(grads, opt_state, params)
         if compressor is not None:
             new_opt = {"_opt": new_opt, "_compress_residual": residual}
         metrics["loss"] = loss
@@ -195,27 +205,36 @@ class Trainer:
         metrics = {}
         target = self.state.step + num_steps
         while self.state.step < target:
-            batch = next(self.data_iter)
-            t0 = time.perf_counter()
-            if self._has_aux:
-                params, opt_state, aux, metrics = self.step_fn(
-                    self.state.params, self.state.opt_state,
-                    self.state.aux, batch)
-            else:
-                params, opt_state, metrics = self.step_fn(
-                    self.state.params, self.state.opt_state, batch)
-                aux = self.state.aux
-            jax.block_until_ready(metrics["loss"])
-            dt = time.perf_counter() - t0
-            if self.step_deadline_s is not None and dt > self.step_deadline_s:
-                self.on_straggler(self.state.step, dt)
-            self.state = TrainState(params, opt_state, self.state.step + 1,
-                                    aux)
-            if self.state.step % self.ckpt_every == 0:
-                self.save()
-            if log_every and self.state.step % log_every == 0:
-                log.info("step %d loss %.4f (%.3fs)", self.state.step,
-                         float(metrics["loss"]), dt)
+            # profiler spans (no-ops unless a trace is being recorded):
+            # the host's share of a step, split into fetching the batch,
+            # dispatching the jitted step and waiting for the device
+            with StepTraceAnnotation("train.step",
+                                     step_num=self.state.step):
+                with TraceAnnotation("train.data"):
+                    batch = next(self.data_iter)
+                t0 = time.perf_counter()
+                with TraceAnnotation("train.dispatch"):
+                    if self._has_aux:
+                        params, opt_state, aux, metrics = self.step_fn(
+                            self.state.params, self.state.opt_state,
+                            self.state.aux, batch)
+                    else:
+                        params, opt_state, metrics = self.step_fn(
+                            self.state.params, self.state.opt_state, batch)
+                        aux = self.state.aux
+                with TraceAnnotation("train.wait"):
+                    jax.block_until_ready(metrics["loss"])
+                dt = time.perf_counter() - t0
+                if (self.step_deadline_s is not None
+                        and dt > self.step_deadline_s):
+                    self.on_straggler(self.state.step, dt)
+                self.state = TrainState(params, opt_state,
+                                        self.state.step + 1, aux)
+                if self.state.step % self.ckpt_every == 0:
+                    self.save()
+                if log_every and self.state.step % log_every == 0:
+                    log.info("step %d loss %.4f (%.3fs)", self.state.step,
+                             float(metrics["loss"]), dt)
         if self.ckpt is not None:
             self.save(blocking=True)
             self.ckpt.wait()

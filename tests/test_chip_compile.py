@@ -4,12 +4,17 @@ The TPU compiler is installed with jaxlib: it compiles for a *described*
 ``v5e:2x2`` topology, so unaligned slices, VMEM overruns and Mosaic
 lowering faults that interpret mode cannot see fail here, at no chip
 time.  Nothing runs — each test checks that the compiled program holds
-the kernel (``tpu_custom_call``).
+the kernel (``tpu_custom_call``), in an instruction named after its
+``KernelSpec`` (``bsmm_fwd``, ``bsmm_dx``, ...): the name a profiler
+trace gives the launch.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and every xdist worker
 imports this file.
 """
+import re
+from typing import List
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,9 +62,24 @@ def plan():
     return make_tile_plan(mask, interpret=False, strict=True)
 
 
-def _kernels(fn, *args) -> int:
+# a launch's instruction is named after its kernel, wrapped in the
+# transformations JAX traced it under: ``jvp_bsmm_fwd_``,
+# ``transpose_jvp_bsmm_dx__``
+KINDS = re.compile(r"(?:^|_)(bsmm_fwd_epilogue|bsmm_fwd|bsmm_dx|bsmm_dw|"
+                   r"paged_attention_gqa)(?:_|$)")
+
+
+def _kernels(fn, *args) -> List[str]:
+    """The kernel names of the compiled program's Pallas launches,
+    sorted (an instruction that carries none reads as itself)."""
     text = jax.jit(fn).lower(*args).compile().as_text()
-    return text.count('custom_call_target="tpu_custom_call"')
+    out = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT )?%?([\w.-]+) =", line).group(1)
+            kind = KINDS.search(name.split(".")[0])
+            out.append(kind.group(1) if kind else name)
+    return sorted(out)
 
 
 def _arg(shape, dtype, sharding):
@@ -70,7 +90,8 @@ def _arg(shape, dtype, sharding):
 def test_bsmm_forward_compiles(one_chip, plan, m):
     x = _arg((m, K), jnp.bfloat16, one_chip)
     w = _arg((K, N), jnp.bfloat16, one_chip)
-    assert _kernels(lambda x, w: plan_matmul(x, w, plan), x, w) == 1
+    assert _kernels(lambda x, w: plan_matmul(x, w, plan), x, w) == [
+        "bsmm_fwd"]
 
 
 def test_bsmm_fused_epilogue_compiles(one_chip, plan):
@@ -78,18 +99,33 @@ def test_bsmm_fused_epilogue_compiles(one_chip, plan):
     w = _arg((K, N), jnp.bfloat16, one_chip)
     b = _arg((N,), jnp.bfloat16, one_chip)
     assert _kernels(lambda x, w, b: plan_matmul(x, w, plan, bias=b,
-                                                act="silu"), x, w, b) == 1
+                                                act="silu"), x, w, b) == [
+        "bsmm_fwd_epilogue"]
 
 
-def test_bsmm_forward_backward_compiles(one_chip, plan):
+def _forward_backward(one_chip, plan, remat: bool) -> List[str]:
     x = _arg((2048, K), jnp.bfloat16, one_chip)
     w = _arg((K, N), jnp.bfloat16, one_chip)
 
     def loss(x, w):
         return plan_matmul(x, w, plan).astype(jnp.float32).sum()
 
+    if remat:
+        loss = jax.checkpoint(loss)
+    return _kernels(jax.value_and_grad(loss, argnums=(0, 1)), x, w)
+
+
+def test_bsmm_forward_backward_compiles(one_chip, plan):
     # forward, dx (transposed plan) and dw (live tiles only)
-    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1)), x, w) == 3
+    assert _forward_backward(one_chip, plan, remat=False) == [
+        "bsmm_dw", "bsmm_dx", "bsmm_fwd"]
+
+
+def test_bsmm_forward_backward_keeps_the_names_under_remat(one_chip, plan):
+    # jax.checkpoint rematerialises the forward inside a call: each
+    # launch keeps its kernel's name, not the call's
+    assert _forward_backward(one_chip, plan, remat=True) == [
+        "bsmm_dw", "bsmm_dx", "bsmm_fwd"]
 
 
 @pytest.mark.parametrize("slots,blocks", [(8, 65), (1, 9)])
@@ -103,4 +139,5 @@ def test_paged_attention_gqa_compiles(one_chip, slots, blocks):
         return paged_attention(q, k, v, t, n, scale=HD ** -0.5,
                                interpret=False)
 
-    assert _kernels(attend, q, pool, pool, tables, lens) == 1
+    assert _kernels(attend, q, pool, pool, tables, lens) == [
+        "paged_attention_gqa"]

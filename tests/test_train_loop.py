@@ -1,4 +1,5 @@
-"""Trainer: convergence, microbatching, checkpoint-resume, stragglers."""
+"""Trainer: convergence, microbatching, checkpoint-resume, stragglers,
+profiler spans."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -97,3 +98,65 @@ def test_straggler_callback_fires():
                 on_straggler=lambda step, dt: events.append((step, dt)))
     t.run(3, log_every=0)
     assert len(events) == 3
+
+
+def test_trainer_run_records_its_phases_as_profiler_spans(tmp_path):
+    """Under a profiler session each step of ``Trainer.run`` is a
+    ``train.step`` span holding ``train.data``, ``train.dispatch`` and
+    ``train.wait``, once each and in that order."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    params, loss_fn, gen = _tiny_lm()
+    pipe = DataPipeline(
+        lambda s: {k: jnp.asarray(v) for k, v in gen.batch(s, 8).items()},
+        prefetch=0)
+    t = Trainer(loss_fn=loss_fn, optimizer=adamw(constant(1e-3)),
+                params=params, data_iter=pipe, ckpt_dir=None)
+    t.run(1, log_every=0)                       # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t.run(2, log_every=0)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats))
+                    for p in ProfileData.from_file(path).planes
+                    for line in p.lines for e in line.events
+                    if e.name.startswith("train.")), key=lambda s: s[1])
+    steps = [s for s in spans if s[0] == "train.step"]
+    assert [int(s[3]["step_num"]) for s in steps] == [1, 2]
+    for _, t0, t1, _ in steps:
+        inner = [s for s in spans if s[0] != "train.step"
+                 and t0 <= s[1] and s[2] <= t1]
+        assert [s[0] for s in inner] == ["train.data", "train.dispatch",
+                                         "train.wait"]
+        assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+    assert len(spans) == 4 * len(steps)
+
+
+@pytest.mark.parametrize("aux_state", [False, True])
+def test_optimizer_update_runs_under_its_named_scope(aux_state):
+    """Both step bodies put the update under ``train.optimizer``, the
+    scope a profiler trace groups its device ops by; the gradient's ops
+    stay outside it."""
+    import re
+    from repro.train.loop import OPTIMIZER_SCOPE
+    params, loss_fn, gen = _tiny_lm()
+    opt = adamw(constant(1e-3))
+    batch = {k: jnp.asarray(v) for k, v in gen.batch(0, 4).items()}
+    if aux_state:
+        step = make_train_step(
+            lambda p, s, b: (loss_fn(p, b)[0], (s, {})), opt,
+            donate=False, has_aux_state=True)
+        args = (params, opt.init(params), {"n": jnp.zeros(())}, batch)
+    else:
+        step = make_train_step(loss_fn, opt, donate=False)
+        args = (params, opt.init(params), batch)
+    text = step.lower(*args).as_text(debug_info=True)
+    scopes = set(re.findall(r'loc\("([^"]*)"', text))
+    assert any(f"/{OPTIMIZER_SCOPE}/" in s for s in scopes)
+    assert any("dot_general" in s and OPTIMIZER_SCOPE not in s
+               for s in scopes)
