@@ -29,7 +29,8 @@ import numpy as np
 
 from benchmarks.common import Timer, csv_line
 from repro.core.perf_model import bsmm_train_cost
-from repro.kernels.bsmm import default_interpret, make_tile_plan, plan_matmul
+from repro.kernels.bsmm import (default_interpret, make_tile_plan,
+                                plan_matmul, row_block)
 
 DENSITIES = (1.0, 0.5, 0.25, 0.0625)
 
@@ -88,7 +89,7 @@ def run(M: int = 256, K: int = 512, N: int = 512, b: int = 128,
         predicted_cost = (fwd_frac + dx_frac + dw_frac) / 3.0
         # the K306-audited analytic model: per-kernel passes/FLOPs/HBM
         # bytes for this exact plan (what the TPU regen compares against)
-        cost = bsmm_train_cost(plan, M, bm=b)
+        cost = bsmm_train_cost(plan, M, bm=row_block(M, x.dtype, b)[1])
         rec = {
             "name": f"bsmm_train_density_{density}",
             "shape": [M, K, N],

@@ -28,7 +28,8 @@ operands) host numpy):
         lengths, causal structure).
   K304  accumulator/softmax scratch is f32 and the accumulator shape
         matches the output block it flushes into.
-  K305  VMEM footprint (double-buffered blocks + scratch) within the
+  K305  VMEM footprint (double-buffered blocks + scratch + the body's
+        counted temporaries) within the
         per-backend budget declared in ``configs.base``.
   K306  passes/FLOPs/bytes enumerated from the spec equal
         ``core.perf_model``'s analytic ``KernelCost`` prediction from
@@ -37,7 +38,8 @@ operands) host numpy):
 
 ``default_cases()`` is the canonical registry of small concrete cases
 covering every registered kernel (bsmm fwd plain + fused epilogue, dx,
-dw, paged attention GQA + fused-V MLA, flash attention, masked matmul,
+dw, each also with row blocks taller than a tile and dead plan slots;
+paged attention GQA + fused-V MLA, flash attention, masked matmul,
 tile stats); ``audit_kernels()`` runs them all and is what ``lint
 --kernels`` invokes — the static gate before a chip run.
 """
@@ -267,7 +269,8 @@ def audit_kernel_spec(spec: KernelSpec, *, backend: str = "tpu",
         findings.append(error(
             "K305", where,
             f"estimated VMEM {bd['total']} B (2×in {bd['inputs']} + "
-            f"2×out {bd['outputs']} + scratch {bd['scratch']}) exceeds "
+            f"2×out {bd['outputs']} + scratch {bd['scratch']} + "
+            f"temporaries {bd['temporaries']}) exceeds "
             f"the {budget} B {backend} budget "
             f"(configs.base.VMEM_BUDGET_BYTES)"))
 
@@ -313,22 +316,32 @@ _BITMAP = np.array([[1, 0],
                     [0, 1],
                     [1, 1]], np.int32)
 
+#: a bitmap whose plans have dead slots: forward columns of 2, 0 and 3
+#: live tiles (kmax 3), transposed rows of 2, 1 and 2 (nmax 2)
+_BITMAP_SLOTS = np.array([[1, 0, 1],
+                          [0, 0, 1],
+                          [1, 0, 1]], np.int32)
 
-def _bsmm_cases(tile: int) -> List[AuditCase]:
+
+def _bsmm_cases(tile: int, bitmap: np.ndarray = _BITMAP, rows: int = 1,
+                suffix: str = "") -> List[AuditCase]:
+    """fwd, fused fwd, dx and dw over two row blocks of ``rows`` tiles
+    each; ``suffix`` tells a second geometry's case names from the
+    kernels' own."""
     from repro.core.perf_model import (bsmm_dw_cost, bsmm_dx_cost,
                                        bsmm_fwd_cost)
     from repro.kernels.bsmm import (bsmm_dw_spec, bsmm_dx_spec,
                                     bsmm_fwd_spec, make_tile_plan)
 
-    Kt, Nt = _BITMAP.shape
+    Kt, Nt = bitmap.shape
     K, N = Kt * tile, Nt * tile
-    M, bm = 2 * tile, tile
-    Mt = M // bm
-    mask = np.repeat(np.repeat(_BITMAP, tile, 0), tile, 1)
+    bm, Mt = rows * tile, 2
+    M = Mt * bm
+    mask = np.repeat(np.repeat(bitmap, tile, 0), tile, 1)
     plan = make_tile_plan(mask, tile=tile, strict=True)
 
-    live_k = {j: np.nonzero(_BITMAP[:, j])[0] for j in range(Nt)}
-    live_n = {k: np.nonzero(_BITMAP[k, :])[0] for k in range(Kt)}
+    live_k = {j: np.nonzero(bitmap[:, j])[0] for j in range(Nt)}
+    live_n = {k: np.nonzero(bitmap[k, :])[0] for k in range(Kt)}
     fwd_truth = {
         "x": {(i, j): [(i, int(kt)) for kt in live_k[j]]
               for i in range(Mt) for j in range(Nt)},
@@ -341,7 +354,7 @@ def _bsmm_cases(tile: int) -> List[AuditCase]:
         "w": {(i, k): [(k, int(nt)) for nt in live_n[k]]
               for i in range(Mt) for k in range(Kt)},
     }
-    kk, nn = np.nonzero(_BITMAP)             # row-major, == plan order
+    kk, nn = np.nonzero(bitmap)              # row-major, == plan order
     dw_truth = {
         "x": {(l,): [(m, int(kk[l])) for m in range(Mt)]
               for l in range(len(kk))},
@@ -350,22 +363,22 @@ def _bsmm_cases(tile: int) -> List[AuditCase]:
     }
     cases = [
         AuditCase(
-            "bsmm_fwd",
+            "bsmm_fwd" + suffix,
             bsmm_fwd_spec(plan.idx, plan.counts, plan.kmax, M=M, K=K,
                           N=N, bm=bm, bk=tile, bn=tile),
             fwd_truth, bsmm_fwd_cost(plan, M, bm=bm)),
         AuditCase(
-            "bsmm_fwd_epilogue",
+            "bsmm_fwd_epilogue" + suffix,
             bsmm_fwd_spec(plan.idx, plan.counts, plan.kmax, M=M, K=K,
                           N=N, bm=bm, bk=tile, bn=tile, fused=True),
             fwd_truth, bsmm_fwd_cost(plan, M, bm=bm, fused=True)),
         AuditCase(
-            "bsmm_dx",
+            "bsmm_dx" + suffix,
             bsmm_dx_spec(plan.idx_t, plan.counts_t, plan.nmax, M=M,
                          K=K, N=N, bm=bm, tile=tile),
             dx_truth, bsmm_dx_cost(plan, M, bm=bm)),
         AuditCase(
-            "bsmm_dw",
+            "bsmm_dw" + suffix,
             bsmm_dw_spec(plan.kk, plan.nn, M=M, K=K, N=N, bm=bm,
                          tile=tile),
             dw_truth, bsmm_dw_cost(plan, M, bm=bm)),
@@ -437,13 +450,18 @@ def _flash_case(tile: int) -> AuditCase:
 
 def default_cases(tile: int = MXU_TILE) -> List[AuditCase]:
     """The canonical small concrete launches, one per registered
-    kernel.  ``masked_matmul``/``tile_stats`` carry no liveness truth
-    or cost (their work gates are data-dependent / VPU-only), so K303
-    and K306 are skipped for them by construction."""
+    kernel under its own name, and the bsmm kernels once more at a
+    second geometry (``.rows``).  ``masked_matmul``/``tile_stats``
+    carry no liveness truth or cost (their work gates are
+    data-dependent / VPU-only), so K303 and K306 are skipped for them
+    by construction."""
     from repro.kernels.bsmm import masked_matmul_spec
     from repro.kernels.tile_stats import tile_stats_spec
 
     cases = _bsmm_cases(tile)
+    # row blocks taller than a tile (``bsmm.row_block``) over plans
+    # whose dead slots repeat a column's last live index
+    cases.extend(_bsmm_cases(tile, _BITMAP_SLOTS, rows=2, suffix=".rows"))
     cases.extend(_paged_cases())
     cases.append(_flash_case(tile))
     cases.append(AuditCase(

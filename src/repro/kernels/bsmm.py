@@ -12,10 +12,16 @@ so index maps can steer the DMA engine):
     w block   (bk, bn) at (idx[j,k], j)
     out block (bm, bn) at (i, j), f32 VMEM accumulator
 
-Tiles beyond a column's live count are masked with ``pl.when`` (their
-DMA re-reads a valid tile; no wrong data is accumulated).  Compute and
+Slots beyond a column's live count are masked with ``pl.when`` and
+repeat the column's last live index, so their block index does not
+change and the pipeline fetches nothing for them.  Compute and
 bandwidth both scale with the *live tile count* — the paper's hardware
 savings, as FLOP/byte savings.
+
+The row block ``bm`` is as tall as VMEM allows (``row_block``): a
+retrain batch of a few thousand rows is one row block, so each grid
+step issues a (bm, bk) × (bk, bn) product rather than one 128³ tile,
+and the fixed cost of a grid step is paid once per live tile.
 
 The mask is static at compile time (pruning is a one-time offline step,
 paper §V.C), so the compacted indices are baked in as constants.
@@ -31,7 +37,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.configs.base import MXU_TILE
+from repro.configs.base import MXU_TILE, vmem_budget
 from repro.kernels.spec import BlockMap, KernelSpec, ScratchSpec
 from repro.models import hooks
 
@@ -103,7 +109,9 @@ def compact_tile_indices(tile_mask: np.ndarray) -> Tuple[np.ndarray,
     """Per column j of the (Kt, Nt) tile mask: live k indices + counts.
 
     Returns (idx (Nt, KMAX) int32, count (Nt,) int32, KMAX).
-    Dead slots point at tile 0 (valid DMA target, masked in-kernel).
+    Dead slots (masked in-kernel) repeat the column's last live index,
+    or tile 0 for an empty column: a valid DMA target whose block index
+    equals the previous step's, so the pipeline skips the copy.
     """
     tm = np.asarray(tile_mask) != 0
     Kt, Nt = tm.shape
@@ -112,7 +120,9 @@ def compact_tile_indices(tile_mask: np.ndarray) -> Tuple[np.ndarray,
     idx = np.zeros((Nt, kmax), np.int32)
     for j in range(Nt):
         live = np.nonzero(tm[:, j])[0]
-        idx[j, : len(live)] = live
+        if len(live):
+            idx[j, : len(live)] = live
+            idx[j, len(live):] = live[-1]
     return idx, counts, kmax
 
 
@@ -227,6 +237,9 @@ def bsmm_fwd_spec(idx, counts, kmax: int, *, M: int, K: int, N: int,
                           lambda i, j, k, cnt, idx: (i, j),
                           (M, N), dtype),),
         scratch=(ScratchSpec((bm, bn), jnp.float32, "accumulator"),),
+        # the flush's f32 ``acc + b`` and the activation's intermediate
+        temporaries=(ScratchSpec((2, bm, bn), jnp.float32, "other"),)
+        if fused else (),
         scalars=(counts, idx),
         guard=lambda i, j, k, cnt, idx: bool(k < cnt[j]),
         cell_flops=2.0 * bm * bk * bn,
@@ -583,16 +596,57 @@ def bsmm_apply(x2, w, plan: TilePlan, *, bm: int, bias=None,
     return f(x2, w, b)
 
 
+def row_block_fits(bm: int, dtype, tile: int = MXU_TILE) -> bool:
+    """Whether the forward, fused forward, dx and dw launches at row
+    block ``bm`` each fit ``vmem_budget("tpu")`` by their own specs'
+    ``vmem_breakdown`` (blocks, accumulator, the epilogue's f32
+    temporaries)."""
+    one, idx = np.ones(1, np.int32), np.zeros((1, 1), np.int32)
+    geo = dict(M=bm, K=tile, N=tile, bm=bm, dtype=dtype)
+    specs = (
+        bsmm_fwd_spec(idx, one, 1, bk=tile, bn=tile, **geo),
+        bsmm_fwd_spec(idx, one, 1, bk=tile, bn=tile, fused=True, **geo),
+        bsmm_dx_spec(idx, one, 1, tile=tile, **geo),
+        bsmm_dw_spec(idx[0], idx[0], tile=tile, **geo),
+    )
+    return all(s.vmem_bytes() <= vmem_budget("tpu") for s in specs)
+
+
+@functools.lru_cache(maxsize=None)
+def row_block(M: int, dtype, tile: int = MXU_TILE) -> Tuple[int, int]:
+    """Rows ``M`` of a routed matmul → (padded rows Mp, row block bm).
+
+    M pads to a sublane multiple (8); below one tile that is the whole
+    block, so a decode batch of a few slots stays one small block.  From
+    one tile up Mp pads to a tile multiple, and bm is the largest tile
+    multiple that divides Mp and ``row_block_fits``.  A grid step then
+    issues a (bm, tile) × (tile, tile) product, and the fixed cost of a
+    step is paid once per live tile rather than once per live tile and
+    128-row block.
+    """
+    Mp = M + (-M % 8)
+    if Mp < tile:
+        return Mp, Mp
+    Mp += -Mp % tile
+    n = Mp // tile
+    for d in range(n, 0, -1):
+        if n % d == 0 and row_block_fits(d * tile, dtype, tile):
+            return Mp, d * tile
+    return Mp, tile
+
+
 def plan_matmul(x, w, plan: Optional[TilePlan], bias=None,
                 act: Optional[str] = None):
     """x (..., K) @ w (K, N) routed through the block-sparse kernel.
 
-    ``plan=None`` is the dense path.  Rows are zero-padded up to a
-    sublane multiple (decode batches are tiny: a handful of slots;
-    retrain microbatches are ragged), so compute/bandwidth still scales
-    with the live-tile count along K — the dimension pruning actually
-    thins.  Differentiable: gradients flow through the custom-VJP
-    block-sparse backward kernels (``bsmm_apply``).
+    ``plan=None`` is the dense path.  Rows are zero-padded and blocked
+    by ``row_block``: a decode batch of a few slots is one sublane-
+    padded block; a retrain or prefill batch pads to a tile multiple
+    and runs in as few row blocks as VMEM allows (one, for a few
+    thousand rows), so compute/bandwidth still scales with the live-tile
+    count along K — the dimension pruning actually thins.
+    Differentiable: gradients flow through the custom-VJP block-sparse
+    backward kernels (``bsmm_apply``).
 
     ``bias``/``act`` fuse the bias-add and activation into the kernel's
     flush (``bsmm_apply`` epilogue); the dense fallback applies them
@@ -621,15 +675,8 @@ def plan_matmul(x, w, plan: Optional[TilePlan], bias=None,
             shape=(K, N), tile=plan.tile, where="plan_matmul")
     M = int(np.prod(lead)) if lead else 1
     x2 = x.reshape(M, K)
-    # pad M to a multiple of 8 (f32 sublane); large M tiles at 128
-    mp = -M % 8
-    Mp = M + mp
-    if Mp >= plan.tile:
-        mp += -Mp % plan.tile
-        Mp = M + mp
-        bm = plan.tile
-    else:
-        bm = Mp
+    Mp, bm = row_block(M, jnp.promote_types(x.dtype, w.dtype), plan.tile)
+    mp = Mp - M
     if mp:
         x2 = jnp.pad(x2, ((0, mp), (0, 0)))
     # padded rows come out as act(bias) garbage; they are sliced off below

@@ -35,9 +35,11 @@ def sparse_dense(x, w, mask: np.ndarray, *, bk: int = MXU_TILE,
     Differentiable: forward and both backward matmuls run block-sparse
     (``bsmm.bsmm_apply``); the explicit ``w * mask`` keeps the weight
     gradient elementwise-exact vs the dense masked oracle.  Ragged M
-    (small retrain batches) is zero-padded to a sublane multiple inside
-    ``plan_matmul``, which also picks the row blocking — only ragged
-    K/N (or rectangular bk≠bn tiles) fall back to the dense oracle.
+    (small retrain batches) is zero-padded inside ``plan_matmul``, whose
+    ``bsmm.row_block`` picks the row blocking from M and the dtype: one
+    sublane-padded block below a tile, else as few tile-multiple blocks
+    as VMEM allows — only ragged K/N (or rectangular bk≠bn tiles) fall
+    back to the dense oracle.
     """
     K, N = w.shape
     lead = x.shape[:-1]

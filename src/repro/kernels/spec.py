@@ -76,6 +76,9 @@ class KernelSpec:
     inputs: Tuple[BlockMap, ...]
     outputs: Tuple[BlockMap, ...]
     scratch: Tuple[ScratchSpec, ...] = ()
+    # values the kernel body materialises in VMEM beyond its blocks and
+    # scratch (an epilogue's f32 intermediates): counted, never allocated
+    temporaries: Tuple[ScratchSpec, ...] = ()
     # concrete scalar-prefetch operands, in kernel argument order
     scalars: Tuple[np.ndarray, ...] = ()
     # host mirror of the pl.when work gate: guard(*ids, *scalars) -> bool;
@@ -110,14 +113,17 @@ class KernelSpec:
         """Estimated VMEM residency at the planned block shapes.
 
         Block operands are double-buffered (Pallas pipelines the next
-        block's DMA behind the current compute), scratch is single:
-        ``2·Σ in + 2·Σ out + Σ scratch`` bytes.
+        block's DMA behind the current compute), scratch and the body's
+        temporaries are single:
+        ``2·Σ in + 2·Σ out + Σ scratch + Σ temporaries`` bytes.
         """
         ins = sum(bm.block_bytes for bm in self.inputs)
         outs = sum(bm.block_bytes for bm in self.outputs)
         scr = sum(s.nbytes for s in self.scratch)
+        tmp = sum(s.nbytes for s in self.temporaries)
         return {"inputs": 2 * ins, "outputs": 2 * outs, "scratch": scr,
-                "total": 2 * ins + 2 * outs + scr}
+                "temporaries": tmp,
+                "total": 2 * ins + 2 * outs + scr + tmp}
 
     def vmem_bytes(self) -> int:
         return self.vmem_breakdown()["total"]

@@ -38,6 +38,8 @@ def _grads(fn, *args):
     (64, 256, 256),      # FC shape (all-dead tile column case)
     (5, 256, 128),       # ragged-M retrain microbatch
     (3, 128, 384),       # ragged M, wide N
+    (384, 256, 384),     # one 384-row block
+    (2048, 256, 256),    # a 4 x 512 retrain batch: one 2048-row block
 ])
 def test_sparse_dense_grads_match_dense_oracle(M, K, N):
     rng = np.random.RandomState(M * 7 + K + N)
@@ -126,6 +128,43 @@ def test_epilogue_fused_matches_unfused_oracle(act, with_bias):
     go = jax.grad(loss_o, argnums=tuple(range(len(args))))(*args)
     names = ("dx", "dw", "db")[:len(args)]
     for name, a, o in zip(names, gf, go):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(o),
+                                   rtol=1e-4, atol=2e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("M", [384, 2048])
+@pytest.mark.parametrize("act", [None, "silu"])
+def test_epilogue_tall_row_block_matches_dense_oracle(M, act):
+    """One row block of all M rows (``row_block``), over a mask with an
+    all-dead tile column and columns/rows with dead plan slots: the
+    fused forward and its dx/dw/db match the dense masked oracle."""
+    from repro.kernels.bsmm import _EPILOGUE_ACTS, row_block
+    rng = np.random.RandomState(M)
+    K, N = 256, 384
+    mask = _random_mask(rng, K, N)
+    mask[:128, 256:] = 0.0              # column 2 and row 0: one dead slot
+    plan = make_tile_plan(mask)
+    assert (plan.counts.tolist(), plan.counts_t.tolist()) == ([2, 0, 1],
+                                                              [1, 2])
+    assert row_block(M, jnp.dtype(jnp.float32)) == (M, M)
+    x = jnp.asarray(rng.randn(M, K), jnp.float32)
+    w = jnp.asarray(rng.randn(K, N), jnp.float32)
+    b = jnp.asarray(rng.randn(N), jnp.float32)
+    fn = _EPILOGUE_ACTS.get(act, lambda z: z)
+
+    def fused(x, w, b):
+        return plan_matmul(x, w * jnp.asarray(mask), plan, bias=b, act=act)
+
+    def oracle(x, w, b):
+        return fn(ref.masked_matmul_ref(x, w, jnp.asarray(mask)) + b)
+
+    np.testing.assert_allclose(np.asarray(fused(x, w, b)),
+                               np.asarray(oracle(x, w, b)), **TOL)
+    gf = jax.grad(lambda *a: jnp.sum(jnp.sin(fused(*a))),
+                  argnums=(0, 1, 2))(x, w, b)
+    go = jax.grad(lambda *a: jnp.sum(jnp.sin(oracle(*a))),
+                  argnums=(0, 1, 2))(x, w, b)
+    for name, a, o in zip(("dx", "dw", "db"), gf, go):
         np.testing.assert_allclose(np.asarray(a), np.asarray(o),
                                    rtol=1e-4, atol=2e-3, err_msg=name)
 

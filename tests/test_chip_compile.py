@@ -86,7 +86,7 @@ def _arg(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("m", [8, 2048])
+@pytest.mark.parametrize("m", [8, 2048, 3584])
 def test_bsmm_forward_compiles(one_chip, plan, m):
     x = _arg((m, K), jnp.bfloat16, one_chip)
     w = _arg((K, N), jnp.bfloat16, one_chip)
@@ -101,6 +101,23 @@ def test_bsmm_fused_epilogue_compiles(one_chip, plan):
     assert _kernels(lambda x, w, b: plan_matmul(x, w, plan, bias=b,
                                                 act="silu"), x, w, b) == [
         "bsmm_fwd_epilogue"]
+
+
+@pytest.mark.parametrize("m", [2048, 12800])
+def test_bsmm_fused_epilogue_forward_backward_compiles(one_chip, plan, m):
+    # one 2048-row block, and at 12800 rows the tallest block VMEM
+    # allows (6400): the epilogue's f32 temporaries fit the chip too
+    x = _arg((m, K), jnp.bfloat16, one_chip)
+    w = _arg((K, N), jnp.bfloat16, one_chip)
+    b = _arg((N,), jnp.bfloat16, one_chip)
+
+    def loss(x, w, b):
+        y = plan_matmul(x, w, plan, bias=b, act="silu")
+        return y.astype(jnp.float32).sum()
+
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    x, w, b) == ["bsmm_dw", "bsmm_dx", "bsmm_fwd_epilogue",
+                                 "bsmm_fwd_epilogue"]
 
 
 def _forward_backward(one_chip, plan, remat: bool) -> List[str]:

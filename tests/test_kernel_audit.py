@@ -15,6 +15,7 @@ import pytest
 from repro.analysis import (RULES, audit_kernel_spec, audit_kernels,
                             default_cases, explain, rules_markdown)
 from repro.analysis.kernel_audit import audit_case
+from repro.configs.base import MXU_TILE
 from repro.kernels import AUDITED_KERNELS, ScratchSpec
 
 TESTED = set()
@@ -44,7 +45,33 @@ def cases():
 # the clean path: every registered kernel's canonical case audits green
 # ---------------------------------------------------------------------------
 def test_registered_kernels_all_audited(cases):
-    assert set(cases) == set(AUDITED_KERNELS)
+    # every registered kernel has a case under its own name; further
+    # cases (the bsmm ``.rows`` geometry) audit registered kernels only
+    assert set(AUDITED_KERNELS) <= set(cases)
+    assert {c.spec.name for c in cases.values()} == set(AUDITED_KERNELS)
+    assert {n for n in cases if n not in AUDITED_KERNELS} == {
+        "bsmm_fwd.rows", "bsmm_fwd_epilogue.rows", "bsmm_dx.rows",
+        "bsmm_dw.rows"}
+
+
+@pytest.mark.parametrize("name", ["bsmm_fwd.rows", "bsmm_fwd_epilogue.rows",
+                                  "bsmm_dx.rows"])
+def test_bsmm_guarded_slots_repeat_the_previous_block(cases, name):
+    """Row blocks taller than a tile, and every guarded slot after a
+    column's first gathers the blocks of the step before it: an
+    unchanged block index, so the pipeline fetches nothing for it."""
+    spec = cases[name].spec
+    assert spec.inputs[0].block[0] > MXU_TILE
+    cells = list(np.ndindex(*spec.grid))
+    repeats = 0
+    for prev, c in zip(cells, cells[1:]):
+        if c[-1] == 0 or spec.guard(*c, *spec.scalars):
+            continue
+        repeats += 1
+        for bm in spec.inputs:
+            assert tuple(bm.index_map(*c, *spec.scalars)) == \
+                tuple(bm.index_map(*prev, *spec.scalars)), (bm.name, c)
+    assert repeats
 
 
 def test_default_cases_audit_clean():

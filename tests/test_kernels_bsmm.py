@@ -6,7 +6,7 @@ import pytest
 
 from repro.kernels.bsmm import (bsmm_pallas, compact_tile_indices,
                                 make_tile_plan, masked_matmul_pallas,
-                                plan_matmul)
+                                plan_matmul, row_block, row_block_fits)
 from repro.kernels.ops import sparse_dense, tile_bitmap, tile_density
 from repro.kernels.ref import bsmm_ref, expand_tile_mask, masked_matmul_ref
 
@@ -104,6 +104,19 @@ def test_compact_indices_all_dead_column():
     assert idx[1].tolist() == [0, 0, 0, 0]      # masked in-kernel
 
 
+def test_compact_indices_dead_slots_repeat_last_live():
+    """Slots past a column's live count repeat its last live index, so
+    the pipeline sees an unchanged block and fetches nothing; an empty
+    column points at tile 0."""
+    tm = np.array([[1, 0, 0],
+                   [0, 0, 1],
+                   [1, 0, 1],
+                   [0, 0, 1]], np.int32)
+    idx, counts, kmax = compact_tile_indices(tm)
+    assert counts.tolist() == [2, 0, 3] and kmax == 3
+    assert idx.tolist() == [[0, 2, 2], [0, 0, 0], [1, 2, 3]]
+
+
 def test_compact_indices_all_dead_mask_still_one_pass():
     idx, counts, kmax = compact_tile_indices(np.zeros((5, 4), np.int32))
     assert kmax == 1                    # grid dim must stay >= 1
@@ -171,3 +184,26 @@ def test_grid_skips_match_savings():
     idx, counts, kmax = compact_tile_indices(tm)
     assert kmax == 5                      # not 8: 3/8 of passes skipped
     assert counts.tolist() == [2, 5, 0, 0]
+
+
+# -- the row block: as tall as VMEM allows ----------------------------------
+@pytest.mark.parametrize("M,dtype,want", [
+    (3, jnp.float32, (8, 8)),           # decode: one sublane-padded block
+    (3, jnp.bfloat16, (8, 8)),
+    (384, jnp.float32, (384, 384)),
+    (384, jnp.bfloat16, (384, 384)),
+    (2048, jnp.float32, (2048, 2048)),  # a 4 x 512 retrain batch: one block
+    (2048, jnp.bfloat16, (2048, 2048)),
+    (12800, jnp.bfloat16, (12800, 6400)),   # VMEM caps the block below Mp
+    (12800, jnp.float32, (12800, 3200)),
+])
+def test_row_block(M, dtype, want):
+    assert row_block(M, jnp.dtype(dtype)) == want
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_row_block_is_the_largest_that_fits(dtype):
+    Mp, bm = row_block(12800, jnp.dtype(dtype))
+    assert row_block_fits(bm, dtype)
+    taller = [b for b in range(bm + 128, Mp + 1, 128) if Mp % b == 0]
+    assert taller and not any(row_block_fits(b, dtype) for b in taller)
